@@ -155,6 +155,7 @@ from repro.core import mobility, schedule
 from repro.core.cadence import tick_mask
 from repro.data import CaloriesDatasetConfig, dirichlet_partition, make_calories_tabular
 from repro.models import MLPClassifier, MLPClassifierConfig
+from repro.utils.compile_cache import enable_compile_cache
 
 BATCH = 32
 N_CONTRIB = 3
@@ -1640,6 +1641,7 @@ def main() -> None:
     vq.add_argument("-q", "--quiet", action="store_true",
                     help="errors only; progress logging off")
     args = ap.parse_args()
+    enable_compile_cache()
     _setup_logging(1 if args.verbose else -1 if args.quiet else 0)
     run(sizes=tuple(int(s) for s in args.sizes.split(",")),
         smoke=args.smoke, compare=args.compare, out=args.out or None,

@@ -9,12 +9,15 @@ from __future__ import annotations
 import argparse
 import time
 
+from repro.utils.compile_cache import enable_compile_cache
+
 
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default=None,
                     help="comma list: table4,table5,table7,figs,kernels,fleet,roofline")
     args = ap.parse_args()
+    enable_compile_cache()
     only = set(args.only.split(",")) if args.only else None
 
     suites = []

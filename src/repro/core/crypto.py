@@ -7,7 +7,8 @@ validated against the FIPS-197 test vector in the test suite.  Key
 expansion runs host-side in numpy (keys are protocol state, not traced
 values); block encryption is vectorized JAX over blocks so an update
 stream can be enciphered on-accelerator.  ``repro.kernels.aes_ctr``
-provides the Pallas TPU kernel for the same keystream-XOR hot loop.
+holds a Pallas kernel for the same keystream-XOR hot loop that runs in
+interpret mode only: its table gathers do not compile for a TPU.
 """
 
 from __future__ import annotations

@@ -7,13 +7,13 @@ blocks, so the kernel computes the keystream for a tile of counter
 blocks and XORs the payload in the same VMEM pass — the keystream never
 touches HBM.
 
-TPU adaptation note: SubBytes and the GF(2^8) column multiplies are
-byte-table lookups.  A TPU has no scalar byte-gather unit, so the lookup
-tables are passed into VMEM and indexed with vectorized ``jnp.take``;
-this lowers (gather on VMEM) but is not MXU work — on real hardware a
-bitsliced formulation would be preferred.  The kernel is validated in
-interpret mode against the FIPS-197-checked reference; it exists to
-demonstrate the protocol layer can live on-accelerator, per DESIGN.md.
+TPU status: SubBytes and the GF(2^8) column multiplies are byte-table
+lookups, written as vectorized ``jnp.take`` on tables passed into VMEM.
+Mosaic refuses that gather ("Only 2D gather is supported"), so this
+kernel does NOT compile for a TPU; a bitsliced formulation would be
+needed.  It runs in interpret mode only, validated against the
+FIPS-197-checked reference, and neither engine launches it (the loop
+engine's transport uses ``repro.core.crypto``).
 """
 
 from __future__ import annotations

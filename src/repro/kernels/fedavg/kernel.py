@@ -7,10 +7,12 @@ weighting, and the normalization into one pass avoids materializing the
 masked intermediate (which a naive ``(mask*w)[:,None]*updates`` would
 write back to HBM at full N x L size).
 
-Tiling: grid over the flat parameter dimension, block (N, TILE_L) with
-TILE_L = 2048 (16 x 128 lanes) so the working set N*TILE_L*4B stays well
-under VMEM for fleet sizes up to ~256 contributors.  The weight vector
-is small and replicated to every grid step.
+Tiling: grid (R/TR, L/TILE_L), block (TR, N, TILE_L) with TILE_L = 2048
+(16 x 128 lanes).  TR requester sessions share a grid step; it is sized
+by ``repro.kernels.common.tile_rows`` so the block's fp32 VMEM slab
+(N padded to 8 sublanes) stays near 2 MB, and it is the whole R or a
+multiple of 8, which the (TR, N) weight and (TR, TILE_L) output blocks
+need.  The single-session :func:`fedavg_pallas` is the R = 1 case.
 """
 
 from __future__ import annotations
@@ -21,19 +23,19 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.common import resolve_interpret
+from repro.kernels.common import resolve_interpret, slab_bytes, tile_rows
 from repro.kernels.quantize.kernel import TILE as Q_TILE
+from repro.kernels.quantize.kernel import tile_scale
 
 TILE_L = 2048
 
 
-def _fedavg_kernel(w_ref, u_ref, o_ref):
-    """w_ref: (N,) fp32; u_ref: (N, TILE_L); o_ref: (TILE_L,)."""
-    w = w_ref[...]
-    u = u_ref[...].astype(jnp.float32)
-    num = jnp.einsum("n,nl->l", w, u)
-    denom = jnp.maximum(jnp.sum(w), 1e-9)
-    o_ref[...] = num / denom
+def _weighted_sum(w, u):
+    """sum_n w[r, n] * u[r, n, :] -> (TR, TILE) as a batched matmul with
+    a unit row dim (Mosaic has no batched dot without one), at fp32
+    precision on the TPU's MXU."""
+    return jnp.einsum("rmn,rnl->rml", w[:, None, :], u,
+                      precision=jax.lax.Precision.HIGHEST)[:, 0, :]
 
 
 def _fedavg_batched_kernel(w_ref, u_ref, o_ref):
@@ -45,25 +47,19 @@ def _fedavg_batched_kernel(w_ref, u_ref, o_ref):
     small: interpret mode (the CPU path) walks grid steps serially with
     per-step overhead, so a (R, L/TILE_L) grid turned the aggregation
     into the R=512 scaling cliff; (R/TR, L/TILE_L) removes it while the
-    (TR, N, TILE_L) block stays VMEM-bounded on TPU (see _tile_r).
+    (TR, N, TILE_L) block stays VMEM-bounded on TPU.
     """
     w = w_ref[...]
     u = u_ref[...].astype(jnp.float32)
-    num = jnp.einsum("rn,rnl->rl", w, u)
+    num = _weighted_sum(w, u)
     denom = jnp.maximum(jnp.sum(w, axis=1, keepdims=True), 1e-9)
     o_ref[...] = num / denom
 
 
-def _tile_r(r: int, n: int, tile_l: int, itemsize: int) -> int:
-    """Requester-axis tile: as many sessions per grid step as keep the
-    update block within a ~2 MB VMEM budget (double-buffered well under
-    the ~16 MB/core ceiling), at least 1, at most R."""
-    return max(1, min(r, (2 << 20) // max(n * tile_l * itemsize, 1)))
-
-
 def _fedavg_batched_q8_kernel(w_ref, q_ref, s_ref, o_ref):
     """w_ref: (TR, N) fp32; q_ref: (TR, N, Q_TILE) int8; s_ref:
-    (TR, N, 1) fp32 per-tile scales; o_ref: (TR, Q_TILE) fp32.
+    (TR, N, Lp/Q_TILE) fp32, every tile's scale (this step's column is
+    picked by ``tile_scale``); o_ref: (TR, Q_TILE) fp32.
 
     The compressed round state's hot path: dequantize every
     contributor's int8 tile (``q * scale``, the exact wire inverse) and
@@ -73,8 +69,8 @@ def _fedavg_batched_q8_kernel(w_ref, q_ref, s_ref, o_ref):
     :func:`_fedavg_batched_kernel` to keep the grid small.
     """
     w = w_ref[...]
-    u = q_ref[...].astype(jnp.float32) * s_ref[...]
-    num = jnp.einsum("rn,rnl->rl", w, u)
+    u = q_ref[...].astype(jnp.float32) * tile_scale(s_ref[...])
+    num = _weighted_sum(w, u)
     denom = jnp.maximum(jnp.sum(w, axis=1, keepdims=True), 1e-9)
     o_ref[...] = num / denom
 
@@ -85,8 +81,8 @@ def fedavg_batched_q8_pallas(q, scales, weights, *, interpret=None):
     (R, N, Lp/Q_TILE) fp32; weights: (R, N).  Returns (R, Lp) fp32.
 
     The q8 counterpart of :func:`fedavg_batched_pallas`: grid
-    (R/TR, Lp/Q_TILE) — one quantization tile per trailing grid step so
-    each block sees exactly one scale scalar per contributor — with the
+    (R/TR, Lp/Q_TILE) — one quantization tile per trailing grid step, so
+    each step dequantizes with one scale per contributor — with the
     dequant fused into the reduction.  Used by ``repro.core.fleet``
     under ``EnFedConfig.compress="int8"`` to aggregate every concurrent
     session straight from the compressed round-state buffer.
@@ -96,7 +92,7 @@ def fedavg_batched_q8_pallas(q, scales, weights, *, interpret=None):
     if lp % Q_TILE:
         raise ValueError(f"fedavg_batched_q8 needs Lp % {Q_TILE} == 0 "
                          f"(got {lp}); the wire format is tile-padded")
-    tr = _tile_r(r, n, Q_TILE, 1)
+    tr = tile_rows(r, slab_bytes(n, Q_TILE))
     pad_r = (-r) % tr
     if pad_r:
         q = jnp.pad(q, ((0, pad_r), (0, 0), (0, 0)))
@@ -109,7 +105,7 @@ def fedavg_batched_q8_pallas(q, scales, weights, *, interpret=None):
         in_specs=[
             pl.BlockSpec((tr, n), lambda i, j: (i, 0)),
             pl.BlockSpec((tr, n, Q_TILE), lambda i, j: (i, 0, j)),
-            pl.BlockSpec((tr, n, 1), lambda i, j: (i, 0, j)),
+            pl.BlockSpec((tr, n, lp // Q_TILE), lambda i, j: (i, 0, 0)),
         ],
         out_specs=pl.BlockSpec((tr, Q_TILE), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((r + pad_r, lp), jnp.float32),
@@ -133,7 +129,7 @@ def fedavg_batched_pallas(updates, weights, *, interpret=None):
     if pad:
         updates = jnp.pad(updates, ((0, 0), (0, 0), (0, pad)))
     lp = l + pad
-    tr = _tile_r(r, n, TILE_L, 4)
+    tr = tile_rows(r, slab_bytes(n, TILE_L))
     pad_r = (-r) % tr
     if pad_r:
         updates = jnp.pad(updates, ((0, pad_r), (0, 0), (0, 0)))
@@ -155,26 +151,8 @@ def fedavg_batched_pallas(updates, weights, *, interpret=None):
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def fedavg_pallas(updates, weights, *, interpret=None):
-    """updates: (N, L); weights: (N,). Returns (L,) fp32.
-
-    L is padded to a TILE_L multiple internally; callers pass any L.
-    """
-    interpret = resolve_interpret(interpret)
-    n, l = updates.shape
-    pad = (-l) % TILE_L
-    if pad:
-        updates = jnp.pad(updates, ((0, 0), (0, pad)))
-    lp = l + pad
-    grid = (lp // TILE_L,)
-    out = pl.pallas_call(
-        _fedavg_kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((n,), lambda i: (0,)),
-            pl.BlockSpec((n, TILE_L), lambda i: (0, i)),
-        ],
-        out_specs=pl.BlockSpec((TILE_L,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((lp,), jnp.float32),
-        interpret=interpret,
-    )(weights.astype(jnp.float32), updates)
-    return out[:l]
+    """updates: (N, L); weights: (N,). Returns (L,) fp32 — the batched
+    kernel on one session.  L is padded to a TILE_L multiple internally;
+    callers pass any L."""
+    return fedavg_batched_pallas(updates[None], weights[None],
+                                 interpret=interpret)[0]

@@ -25,11 +25,13 @@ bit-identical arithmetic to the dense kernels, so the loop engine
 agree bitwise on every order statistic and clip decision.
 
 Tiling matches ``repro.kernels.fedavg.kernel``: grid
-(R/TR, L/TILE), block (TR, N, TILE), requester tile sized to a ~2 MB
-VMEM budget.  The contributor axis N is small (n_max-bounded), so the
-per-coordinate order statistics run as a static odd-even transposition
-network / one-hot selections along axis 1 — no dynamic gather, Pallas-
-lowerable on TPU.
+(R/TR, L/TILE), block (TR, N, TILE), requester tile from
+``repro.kernels.common.tile_rows``; the q8 twins read every tile's
+scale in one full-width block and pick their column with
+``repro.kernels.quantize.kernel.tile_scale``.  The contributor axis N
+is small (n_max-bounded), so the per-coordinate order statistics run as
+a static odd-even transposition network / one-hot selections along
+axis 1 — no dynamic gather, Pallas-lowerable on TPU.
 """
 
 from __future__ import annotations
@@ -40,22 +42,17 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.common import resolve_interpret
+from repro.kernels.common import resolve_interpret, slab_bytes, tile_rows
 from repro.kernels.quantize.kernel import TILE as Q_TILE
+from repro.kernels.quantize.kernel import tile_scale
 
 TILE_L = 2048
 
 
-def _tile_r(r: int, n: int, tile_l: int, itemsize: int) -> int:
-    """Requester-axis tile under a ~2 MB VMEM budget (see
-    ``repro.kernels.fedavg.kernel._tile_r``)."""
-    return max(1, min(r, (2 << 20) // max(n * tile_l * itemsize, 1)))
-
-
 def _dequant(q, s):
-    """Exact wire inverse ``q * scale`` for one (TR, N, TILE) block with
-    per-block scales (TR, N, 1)."""
-    return q.astype(jnp.float32) * s
+    """Exact wire inverse ``q * scale`` for one (TR, N, TILE) block, the
+    scale picked from the (TR, N, Lp/TILE) block of every tile's."""
+    return q.astype(jnp.float32) * tile_scale(s)
 
 
 # ---------------------------------------------------------------------------
@@ -74,8 +71,9 @@ def _trimmed_mean_block(w, u):
     """
     u = u.astype(jnp.float32)
     n = u.shape[1]
-    act = (w > 0.0)[:, :, None]                      # (TR, N, 1)
-    wb = jnp.where(act, w[:, :, None], 0.0)          # (TR, N, 1)
+    w3 = w[:, :, None]                               # (TR, N, 1)
+    act = w3 > 0.0
+    wb = jnp.where(act, w3, 0.0)
     m3 = jnp.sum(act.astype(jnp.int32), axis=1, keepdims=True)  # (TR, 1, 1)
     n_idx = jax.lax.broadcasted_iota(jnp.int32, u.shape, 1)
     vmax_in = jnp.where(act, u, -jnp.inf)
@@ -131,18 +129,18 @@ def _median_block(w, u):
     """
     u = u.astype(jnp.float32)
     n = u.shape[1]
-    act = (w > 0.0)[:, :, None]
-    m = jnp.sum((w > 0.0).astype(jnp.int32), axis=1)     # (TR,)
+    act = w[:, :, None] > 0.0
+    m = jnp.sum(act.astype(jnp.int32), axis=1)          # (TR, 1)
     rows = _sorted_rows(jnp.where(act, u, jnp.inf), n)
-    lo = jnp.maximum((m - 1) // 2, 0)[:, None]           # (TR, 1)
-    hi = jnp.maximum(m // 2, 0)[:, None]
+    lo = jnp.maximum((m - 1) // 2, 0)
+    hi = jnp.maximum(m // 2, 0)
     vlo = rows[0] * 0.0
     vhi = rows[0] * 0.0
     for j in range(n):
         vlo = jnp.where(lo == j, rows[j], vlo)
         vhi = jnp.where(hi == j, rows[j], vhi)
     med = 0.5 * (vlo + vhi)
-    return jnp.where((m > 0)[:, None], med, 0.0)
+    return jnp.where(m > 0, med, 0.0)
 
 
 def _median_batched_kernel(w_ref, u_ref, o_ref):
@@ -201,7 +199,7 @@ def _launch_dense(kernel, updates, weights, interpret):
     if pad:
         updates = jnp.pad(updates, ((0, 0), (0, 0), (0, pad)))
     lp = l + pad
-    tr = _tile_r(r, n, TILE_L, 4)
+    tr = tile_rows(r, slab_bytes(n, TILE_L))
     pad_r = (-r) % tr
     if pad_r:
         updates = jnp.pad(updates, ((0, pad_r), (0, 0), (0, 0)))
@@ -222,13 +220,13 @@ def _launch_dense(kernel, updates, weights, interpret):
 
 
 def _launch_q8(kernel, q, scales, weights, interpret):
-    """Shared (R, N, Lp) int8 launch: one Q_TILE per trailing grid step
-    so each block sees exactly one scale scalar per contributor."""
+    """Shared (R, N, Lp) int8 launch: one Q_TILE per trailing grid step,
+    so each step dequantizes with one scale per contributor."""
     r, n, lp = q.shape
     if lp % Q_TILE:
         raise ValueError(f"robust q8 kernels need Lp % {Q_TILE} == 0 "
                          f"(got {lp}); the wire format is tile-padded")
-    tr = _tile_r(r, n, Q_TILE, 1)
+    tr = tile_rows(r, slab_bytes(n, Q_TILE))
     pad_r = (-r) % tr
     if pad_r:
         q = jnp.pad(q, ((0, pad_r), (0, 0), (0, 0)))
@@ -241,7 +239,7 @@ def _launch_q8(kernel, q, scales, weights, interpret):
         in_specs=[
             pl.BlockSpec((tr, n), lambda i, j: (i, 0)),
             pl.BlockSpec((tr, n, Q_TILE), lambda i, j: (i, 0, j)),
-            pl.BlockSpec((tr, n, 1), lambda i, j: (i, 0, j)),
+            pl.BlockSpec((tr, n, lp // Q_TILE), lambda i, j: (i, 0, 0)),
         ],
         out_specs=pl.BlockSpec((tr, Q_TILE), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((r + pad_r, lp), jnp.float32),
@@ -290,7 +288,7 @@ def sqnorm_batched_pallas(updates, *, interpret=None):
     if pad:
         updates = jnp.pad(updates, ((0, 0), (0, 0), (0, pad)))
     lp = l + pad
-    tr = _tile_r(r, n, TILE_L, 4)
+    tr = tile_rows(r, slab_bytes(n, TILE_L))
     pad_r = (-r) % tr
     if pad_r:
         updates = jnp.pad(updates, ((0, pad_r), (0, 0), (0, 0)))
@@ -315,7 +313,7 @@ def sqnorm_batched_q8_pallas(q, scales, *, interpret=None):
     if lp % Q_TILE:
         raise ValueError(f"robust q8 kernels need Lp % {Q_TILE} == 0 "
                          f"(got {lp}); the wire format is tile-padded")
-    tr = _tile_r(r, n, Q_TILE, 1)
+    tr = tile_rows(r, slab_bytes(n, Q_TILE))
     pad_r = (-r) % tr
     if pad_r:
         q = jnp.pad(q, ((0, pad_r), (0, 0), (0, 0)))
@@ -326,7 +324,7 @@ def sqnorm_batched_q8_pallas(q, scales, *, interpret=None):
         grid=grid,
         in_specs=[
             pl.BlockSpec((tr, n, Q_TILE), lambda i, j: (i, 0, j)),
-            pl.BlockSpec((tr, n, 1), lambda i, j: (i, 0, j)),
+            pl.BlockSpec((tr, n, lp // Q_TILE), lambda i, j: (i, 0, 0)),
         ],
         out_specs=pl.BlockSpec((tr, n), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((r + pad_r, n), jnp.float32),

@@ -10,11 +10,6 @@ Multi-pod:   (2, 16, 16) axes ("pod", "data", "model") = 512 chips
 
 The ``pod`` axis is the EnFed cross-silo client axis for fsdp configs;
 ``data`` doubles as the client axis for everything else (DESIGN.md §5).
-
-``jax.sharding.AxisType`` only exists on jax >= 0.5; on the pinned
-0.4.x toolchain (where every axis is implicitly auto) meshes are built
-without ``axis_types`` so this module stays importable everywhere.
-``AXIS_TYPES_SUPPORTED`` is the feature gate tests key off.
 """
 
 from __future__ import annotations
@@ -22,14 +17,7 @@ from __future__ import annotations
 import numpy as np
 
 import jax
-from jax.sharding import Mesh
-
-try:  # jax >= 0.5
-    from jax.sharding import AxisType
-except ImportError:  # pinned 0.4.x: axes are implicitly auto-typed
-    AxisType = None
-
-AXIS_TYPES_SUPPORTED = AxisType is not None
+from jax.sharding import AxisType, Mesh
 
 
 def _mesh(shape, axes):
@@ -41,9 +29,7 @@ def _mesh(shape, axes):
             "the dry-run driver must set XLA_FLAGS=--xla_force_host_platform_"
             "device_count=512 before any jax import")
     devs = np.asarray(devices[:n]).reshape(shape)
-    if AXIS_TYPES_SUPPORTED:
-        return Mesh(devs, axes, axis_types=(AxisType.Auto,) * len(shape))
-    return Mesh(devs, axes)
+    return Mesh(devs, axes, axis_types=(AxisType.Auto,) * len(shape))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

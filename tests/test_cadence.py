@@ -34,12 +34,29 @@ from repro.core.faults import FaultConfig
 
 from test_fleet_engine import BATCH, _build
 
-# seed 0 hashes the requester (id 1<<22) to stride 2 under two speed
-# classes — the REQUESTER idles between its rounds; seed 5 hashes it to
-# stride 1 while two of the three contributors land on stride 2 — the
+_REQ = CadenceConfig().requester_id
+_CONTRIB_IDS = list(range(3))       # make_fleet's device ids in _build
+
+
+def _two_class_seed(pred):
+    """The first two-speed-class cadence whose hashed world satisfies
+    ``pred``.  Which stride a seed hashes to is up to the installed
+    ``jax.random``, so the worlds below are chosen by the property the
+    tests need, not pinned to a seed number."""
+    return next(cc for cc in (CadenceConfig(n_speed_classes=2, seed=s)
+                              for s in range(256)) if pred(cc))
+
+
+# the REQUESTER on stride 2, ticking on odd event steps: it idles
+# between its rounds
+CC_SLOW_REQ = _two_class_seed(
+    lambda cc: int(cadence_mod.speed_stride(cc, _REQ)) == 2
+    and bool(cadence_mod.tick_mask(cc, 1, _REQ)))
+# the requester on stride 1 while a contributor lands on stride 2 — the
 # requester outpaces its STRAGGLERS (asserted below, not assumed)
-CC_SLOW_REQ = CadenceConfig(n_speed_classes=2, seed=0)
-CC_STRAGGLER = CadenceConfig(n_speed_classes=2, seed=5)
+CC_STRAGGLER = _two_class_seed(
+    lambda cc: int(cadence_mod.speed_stride(cc, _REQ)) == 1
+    and int(cadence_mod.speed_stride(cc, _CONTRIB_IDS).max()) == 2)
 
 
 @pytest.fixture(scope="module")

@@ -8,19 +8,9 @@ import textwrap
 
 import pytest
 
-from repro.launch.mesh import AXIS_TYPES_SUPPORTED
-
 # each test spawns a fresh interpreter with 8 fake devices and re-jits
-# from scratch; tier-1 skips them, run with -m slow.  repro.launch.mesh
-# itself imports fine on the pinned 0.4.x toolchain (AxisType gated),
-# but these tests exercise shard_map vma/pcast semantics that ship with
-# jax >= 0.5 — skip them cleanly below that.
-pytestmark = [
-    pytest.mark.slow,
-    pytest.mark.skipif(
-        not AXIS_TYPES_SUPPORTED,
-        reason="shard_map vma/pcast semantics need jax.sharding.AxisType (jax>=0.5)"),
-]
+# from scratch; tier-1 skips them, run with -m slow.
+pytestmark = pytest.mark.slow
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 

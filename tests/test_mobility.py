@@ -236,15 +236,14 @@ def test_mobility_config_validation():
 
 
 # ---------------------------------------------------------------------------
-# launch.mesh stays importable on the pinned toolchain (version gate)
+# launch.mesh imports on the installed toolchain
 # ---------------------------------------------------------------------------
 
 
 def test_launch_mesh_imports_on_pinned_jax():
-    """repro.launch.mesh must import (and fail loudly only on device
-    COUNT, never on AxisType) regardless of the jax version."""
+    """repro.launch.mesh imports on the jax the CI pins, and a mesh
+    larger than the host fails loudly on the device COUNT."""
     from repro.launch import mesh
 
-    assert isinstance(mesh.AXIS_TYPES_SUPPORTED, bool)
     with pytest.raises(RuntimeError, match="devices"):
         mesh.make_production_mesh()

@@ -18,8 +18,8 @@ import pytest
 from jax.flatten_util import ravel_pytree
 
 from repro.api import ExecutionSpec, Experiment, MethodSpec, WorldSpec
-from repro.core import (CadenceConfig, FaultConfig, MobilityConfig,
-                        SupervisedTask, make_fleet)
+from repro.core import (FaultConfig, MobilityConfig, SupervisedTask,
+                        make_fleet)
 from repro.data import (CaloriesDatasetConfig, dirichlet_partition,
                         make_calories_tabular)
 from repro.models import MLPClassifier, MLPClassifierConfig
@@ -27,6 +27,8 @@ from repro.telemetry import (EVENT_PHASES, RoundEvent, Timeline, TraceConfig,
                              compare_event_streams, read_events_jsonl,
                              timeline_chrome_trace, validate_events,
                              write_chrome_trace, write_events_jsonl)
+
+from test_cadence import CC_SLOW_REQ
 
 BATCH = 16
 
@@ -60,9 +62,9 @@ _METHOD = MethodSpec(desired_accuracy=0.99, max_rounds=2, epochs=1,
 _MOB = MobilityConfig(radio_range_m=95.0, leg_rounds=1, seed=5)
 _FAULTS = FaultConfig(p_drop=0.6, p_stale=0.4, max_retries=1,
                       release_after=2, seed=3)
-# seed 0 puts the requester on stride 2 of 2 — real idle steps between
-# rounds, so the async observability fields carry non-trivial values
-_CADENCE = CadenceConfig(n_speed_classes=2, seed=0)
+# the requester on stride 2 of 2 — real idle steps between rounds, so
+# the async observability fields carry non-trivial values
+_CADENCE = CC_SLOW_REQ
 
 # world name -> (mobility, method) — the weather regimes the house rule
 # is enforced on (cadence = the async event-step world of PR 9)
