@@ -17,6 +17,7 @@ from typing import Dict, Sequence, Union
 from repro.api.methods import get_runner, method_names
 from repro.api.result import CompareResult, RunResult
 from repro.api.specs import ExecutionSpec, MethodSpec, WorldSpec
+from repro.telemetry.profile import maybe_jax_profiler
 
 DEFAULT_COMPARISON = ("enfed", "dfl", "cfl", "cloud")
 
@@ -49,14 +50,15 @@ class Experiment:
         runner = get_runner(spec.name)
         execution = (self.execution if resume is None else
                      dataclasses.replace(self.execution, resume_from=resume))
-        t0 = time.perf_counter()
-        result = runner(self.world, spec, execution)
-        result.wall_s = time.perf_counter() - t0
+        tr = execution.trace
+        with maybe_jax_profiler(getattr(tr, "jax_profiler_dir", None)):
+            t0 = time.perf_counter()
+            result = runner(self.world, spec, execution)
+            result.wall_s = time.perf_counter() - t0
         result.method = spec.key
         # observability exports happen HERE, after the outcome exists —
         # host-side file I/O only, so tracing can never perturb the run
         # (the telemetry house rule)
-        tr = execution.trace
         if tr is not None:
             from repro.telemetry import write_chrome_trace, write_events_jsonl
 

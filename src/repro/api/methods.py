@@ -130,19 +130,18 @@ def _warn_if_checkpoint_ignored(execution: ExecutionSpec, name: str) -> None:
 
 
 def _warn_if_trace_fleet_only(execution: ExecutionSpec, name: str) -> None:
-    """``TraceConfig.jax_profiler_dir`` / ``hlo_stats`` instrument THE
-    compiled fleet program — the loop engine (and the host-side
-    baselines) has no such program to profile.  Never-silent rule:
-    asking for them on a loop run warns instead of quietly exporting
-    nothing.  The outcome-neutral selections (events_jsonl,
-    chrome_trace) work on every engine and stay silent."""
+    """``TraceConfig.hlo_stats`` compiles THE fleet program — the loop
+    engine (and the host-side baselines) has no such program.
+    Never-silent rule: asking for it on a loop run warns instead of
+    quietly reporting nothing.  The other selections (events_jsonl,
+    chrome_trace, jax_profiler_dir) work on every engine and stay
+    silent."""
     tr = execution.trace
-    if tr is not None and (getattr(tr, "jax_profiler_dir", None)
-                           or getattr(tr, "hlo_stats", False)):
+    if tr is not None and getattr(tr, "hlo_stats", False):
         warnings.warn(
-            f"{name} run ignores TraceConfig.jax_profiler_dir/hlo_stats "
-            "(fleet-engine-only: they profile the compiled fleet program); "
-            "event/timeline exports still apply", stacklevel=3)
+            f"{name} run ignores TraceConfig.hlo_stats (fleet-engine-only: "
+            "it compiles the fleet program); event/timeline exports and "
+            "the profiler still apply", stacklevel=3)
 
 
 def _baseline_model_bytes(params, cfg) -> int:
@@ -177,8 +176,9 @@ def run_enfed(world: WorldSpec, method: MethodSpec,
 
     cfg = method.to_enfed_config(world)
     cost = world.cost_model
-    reqs = world.fresh_requesters()
     tl = Timeline()
+    with tl.span("copy_world"):
+        reqs = world.fresh_requesters()
     if execution.engine == "fleet":
         fr = fleet_mod.run_fleet(
             world.task, reqs, cfg, cost_model=cost,
@@ -188,10 +188,11 @@ def run_enfed(world: WorldSpec, method: MethodSpec,
             checkpoint_every=execution.checkpoint_every,
             resume_from=execution.resume_from,
             timeline=tl, trace=execution.trace)
-        return RunResult.from_sessions(
-            "enfed", "fleet", fr.sessions, cost_model=cost,
-            total_energy_j=fr.total_energy_j, raw=fr,
-            timeline=tl, hlo_stats=fr.hlo_stats)
+        with tl.span("assemble"):
+            return RunResult.from_sessions(
+                "enfed", "fleet", fr.sessions, cost_model=cost,
+                total_energy_j=fr.total_energy_j, raw=fr,
+                timeline=tl, hlo_stats=fr.hlo_stats)
     _warn_if_trace_fleet_only(execution, "loop-engine enfed")
 
     def _sub(root, i):
@@ -237,8 +238,9 @@ def run_enfed(world: WorldSpec, method: MethodSpec,
                 checkpoint_dir=_sub(execution.checkpoint_dir, i),
                 checkpoint_every=execution.checkpoint_every,
                 resume_from=_sub(execution.resume_from, i), timeline=tl))
-    return RunResult.from_sessions("enfed", "loop", sessions, cost_model=cost,
-                                   timeline=tl)
+    with tl.span("assemble"):
+        return RunResult.from_sessions("enfed", "loop", sessions,
+                                       cost_model=cost, timeline=tl)
 
 
 def _run_baseline_fleet(world: WorldSpec, method: MethodSpec,

@@ -255,8 +255,8 @@ class ExecutionSpec:
     # summary).  The strictest execution knob of all — observation can
     # never change the simulated outcome; tracing on is bitwise identical
     # to tracing off (enforced by tests/test_telemetry.py and the bench
-    # trace smoke gate).  Fleet-only selections (jax_profiler_dir,
-    # hlo_stats) warn-and-ignore on the loop engine.
+    # trace smoke gate).  The fleet-only selection (hlo_stats)
+    # warns-and-ignores on the loop engine.
     trace: Optional[object] = None
 
     def __post_init__(self):

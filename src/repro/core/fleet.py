@@ -156,7 +156,7 @@ from repro.kernels.quantize.ops import (dequantize_flat_batched, padded_len,
                                         resolve_compress)
 from repro.models.classifiers import masked_cross_entropy_loss
 from repro.optim import apply_updates
-from repro.telemetry.profile import jit_hlo_stats, maybe_jax_profiler
+from repro.telemetry.profile import jit_hlo_stats
 from repro.telemetry.spans import Timeline
 from repro.utils.tree import (tree_bytes, tree_ravel, tree_size, tree_unravel,
                               tree_where)
@@ -290,6 +290,13 @@ class FleetCarry(NamedTuple):
                               # (robust != "none") | token
 
 
+def _phase_scope(phase: protocol.Phase):
+    """``jax.named_scope`` of one protocol phase: the ops traced inside
+    carry the phase's name in their HLO ``op_name`` metadata, which
+    :func:`repro.telemetry.profile.hlo_phases` maps back to phases."""
+    return jax.named_scope(phase.value)
+
+
 def _make_round_fn(task, use_pallas, interpret, do_refresh, max_rounds,
                    max_events, epochs, batch, steps_max, ref_epochs,
                    ref_steps, spec, mob, n_max, strategy, compress, n_params,
@@ -401,31 +408,32 @@ def _make_round_fn(task, use_pallas, interpret, do_refresh, max_rounds,
         # straight from the deduplicated unique-shard table, gathered
         # per step INSIDE the fit scan — the dedup is never undone into
         # an (R*N, n_c, F) lane-dense block in device memory.
-        nc_pad = arrays["cx_tab"].shape[1]
-        if refresh_dedup:
-            ref_scores = jax.vmap(
-                lambda s: schedule.epoch_scores(s, ref_epochs, nc_pad))(
-                arrays["u_seed"])
-            ref_idx, ref_w = jax.vmap(
-                lambda sc, n: schedule.plan_from_scores(sc, n, batch,
-                                                        ref_steps))(
-                ref_scores, arrays["u_n"])
-            ref_rows = arrays["u_cidx"]
-            uidx_flat = arrays["ref_uidx"].reshape(R * N)
-            # padded contributor slots subscribe to no live row; their
-            # old no-op-refresh contents must survive the scatter
-            lane_valid = arrays["lane_valid"].reshape(R * N, 1)
-        else:
-            ref_scores = jax.vmap(jax.vmap(
-                lambda s: schedule.epoch_scores(s, ref_epochs, nc_pad)))(
-                arrays["ref_seeds"])
-            ref_idx, ref_w = jax.vmap(jax.vmap(
-                lambda sc, n: schedule.plan_from_scores(sc, n, batch,
-                                                        ref_steps)))(
-                ref_scores, arrays["ref_n"])
-            ref_rows = arrays["cidx"].reshape(R * N)
-            ref_idx = ref_idx.reshape(R * N, ref_epochs, ref_steps, batch)
-            ref_w = ref_w.reshape(R * N, ref_epochs, ref_steps, batch)
+        with _phase_scope(protocol.Phase.REFRESH):
+            nc_pad = arrays["cx_tab"].shape[1]
+            if refresh_dedup:
+                ref_scores = jax.vmap(
+                    lambda s: schedule.epoch_scores(s, ref_epochs, nc_pad))(
+                    arrays["u_seed"])
+                ref_idx, ref_w = jax.vmap(
+                    lambda sc, n: schedule.plan_from_scores(sc, n, batch,
+                                                            ref_steps))(
+                    ref_scores, arrays["u_n"])
+                ref_rows = arrays["u_cidx"]
+                uidx_flat = arrays["ref_uidx"].reshape(R * N)
+                # padded contributor slots subscribe to no live row; their
+                # old no-op-refresh contents must survive the scatter
+                lane_valid = arrays["lane_valid"].reshape(R * N, 1)
+            else:
+                ref_scores = jax.vmap(jax.vmap(
+                    lambda s: schedule.epoch_scores(s, ref_epochs, nc_pad)))(
+                    arrays["ref_seeds"])
+                ref_idx, ref_w = jax.vmap(jax.vmap(
+                    lambda sc, n: schedule.plan_from_scores(sc, n, batch,
+                                                            ref_steps)))(
+                    ref_scores, arrays["ref_n"])
+                ref_rows = arrays["cidx"].reshape(R * N)
+                ref_idx = ref_idx.reshape(R * N, ref_epochs, ref_steps, batch)
+                ref_w = ref_w.reshape(R * N, ref_epochs, ref_steps, batch)
 
         def fit_refresh(flat_p, u, idx, w):
             """One refresh row: minibatch (B, F) rows are gathered from
@@ -472,18 +480,20 @@ def _make_round_fn(task, use_pallas, interpret, do_refresh, max_rounds,
         # arrivals, let higher-utility arrivals displace weaker members
         # — all on device, from the traced round number.  Under faults,
         # streak-blocked links lose eligibility here too.
-        if mobility_on:
-            blocked = (faults_mod.blocked_mask(
-                fc, rr, arrays["freq_ids"], arrays["cand_ids"])
-                if faults_on else None)
-            member, rank, _util = mobility_mod.membership_step(
-                mob, rr, arrays["req_ids"], arrays["cand_ids"],
-                arrays["cand_mask"], arrays["base_util"], clevel, n_max,
-                blocked=blocked)
-            round_w = topology.dynamic_round_weights(member, rank, strategy)
-            count = jnp.sum(member, axis=1).astype(jnp.int32)
-        else:
-            round_w = arrays["round_w"]
+        with _phase_scope(protocol.Phase.RENEGOTIATE):
+            if mobility_on:
+                blocked = (faults_mod.blocked_mask(
+                    fc, rr, arrays["freq_ids"], arrays["cand_ids"])
+                    if faults_on else None)
+                member, rank, _util = mobility_mod.membership_step(
+                    mob, rr, arrays["req_ids"], arrays["cand_ids"],
+                    arrays["cand_mask"], arrays["base_util"], clevel, n_max,
+                    blocked=blocked)
+                round_w = topology.dynamic_round_weights(member, rank,
+                                                         strategy)
+                count = jnp.sum(member, axis=1).astype(jnp.int32)
+            else:
+                round_w = arrays["round_w"]
 
         # Phase.DELIVER (faults): which attempting links actually landed
         # an update this round, how many transmissions each burned, and
@@ -491,24 +501,26 @@ def _make_round_fn(task, use_pallas, interpret, do_refresh, max_rounds,
         # delivered mask multiplies straight into the fedavg weights —
         # the kernel's normalized masked mean IS the graceful
         # degradation.
-        if faults_on:
-            delivered, attempts, stale = faults_mod.link_outcomes(
-                fc, rr, arrays["freq_ids"], arrays["fcand_ids"])
-            if mobility_on:
-                att_mask = member           # members attempt; blocked
-                #   links were already released at RENEGOTIATE
-            else:
-                att_mask = arrays["fsigned"] & ~faults_mod.blocked_mask(
+        with _phase_scope(protocol.Phase.DELIVER):
+            if faults_on:
+                delivered, attempts, stale = faults_mod.link_outcomes(
                     fc, rr, arrays["freq_ids"], arrays["fcand_ids"])
-            delivered = delivered & att_mask
-            dcount = jnp.sum(delivered, axis=1).astype(jnp.int32)
-            round_w = round_w * delivered.astype(round_w.dtype)
-            drops_r = jnp.sum(att_mask & ~delivered, axis=1).astype(
-                jnp.float32)
-            retries_r = jnp.sum(jnp.where(att_mask, attempts - 1, 0),
-                                axis=1).astype(jnp.float32)
-            stale_r = jnp.sum(delivered & stale, axis=1).astype(jnp.float32)
-            stale_sel = (delivered & stale)[:, :, None]
+                if mobility_on:
+                    att_mask = member           # members attempt; blocked
+                    #   links were already released at RENEGOTIATE
+                else:
+                    att_mask = arrays["fsigned"] & ~faults_mod.blocked_mask(
+                        fc, rr, arrays["freq_ids"], arrays["fcand_ids"])
+                delivered = delivered & att_mask
+                dcount = jnp.sum(delivered, axis=1).astype(jnp.int32)
+                round_w = round_w * delivered.astype(round_w.dtype)
+                drops_r = jnp.sum(att_mask & ~delivered, axis=1).astype(
+                    jnp.float32)
+                retries_r = jnp.sum(jnp.where(att_mask, attempts - 1, 0),
+                                    axis=1).astype(jnp.float32)
+                stale_r = jnp.sum(delivered & stale,
+                                  axis=1).astype(jnp.float32)
+                stale_sel = (delivered & stale)[:, :, None]
 
         # Phase.COLLECT + Phase.AGGREGATE: one batched kernel launch,
         # directly on the flat round state; under mobility the
@@ -519,81 +531,83 @@ def _make_round_fn(task, use_pallas, interpret, do_refresh, max_rounds,
         # tail dequantizes to zero and is sliced off).  Stale links
         # substitute the second wire-format-resident buffer (``prev``) —
         # the fp32 image never materializes either way.
-        src = jnp.where(stale_sel, prev, contrib) if faults_on else contrib
-        if compress_on:
-            src_s = (jnp.where(stale_sel, prev_s, cscale) if faults_on
-                     else cscale)
-        if adversary_on:
-            # Byzantine corruption at the transport point: AFTER the
-            # stale substitution (ordering pin, protocol.Phase.DELIVER),
-            # keyed on the delivering event step, applied to the wire
-            # image itself (int8 codes/scales under compress — never
-            # re-densified).  The mask derivation is the shared
-            # counter-based closed form, so the loop oracle's per-link
-            # draws match bit for bit.
-            cmask = adversary_mod.corruption_mask(
-                ac, rr, arrays["areq_ids"], arrays["acand_ids"])
+        with _phase_scope(protocol.Phase.AGGREGATE):
+            src = jnp.where(stale_sel, prev, contrib) if faults_on else contrib
             if compress_on:
-                src, src_s = adversary_mod.corrupt_wire_batched(
-                    ac, src, src_s, cmask, rr, arrays["areq_ids"],
-                    arrays["acand_ids"])
-                # the quantization padding tail is not part of the model
-                # update: the loop oracle's dense view slices to P before
-                # any robust statistic, so a noise payload's tail codes
-                # must not leak into the fused q8 clip norms.  Honest
-                # tails are already exact zero codes — this multiply is
-                # the identity for them.
-                if P < src.shape[-1]:
-                    src = src * (jnp.arange(src.shape[-1])
-                                 < P).astype(src.dtype)
+                src_s = (jnp.where(stale_sel, prev_s, cscale) if faults_on
+                         else cscale)
+            if adversary_on:
+                # Byzantine corruption at the transport point: AFTER the
+                # stale substitution (ordering pin, protocol.Phase.DELIVER),
+                # keyed on the delivering event step, applied to the wire
+                # image itself (int8 codes/scales under compress — never
+                # re-densified).  The mask derivation is the shared
+                # counter-based closed form, so the loop oracle's per-link
+                # draws match bit for bit.
+                cmask = adversary_mod.corruption_mask(
+                    ac, rr, arrays["areq_ids"], arrays["acand_ids"])
+                if compress_on:
+                    src, src_s = adversary_mod.corrupt_wire_batched(
+                        ac, src, src_s, cmask, rr, arrays["areq_ids"],
+                        arrays["acand_ids"])
+                    # the quantization padding tail is not part of the model
+                    # update: the loop oracle's dense view slices to P before
+                    # any robust statistic, so a noise payload's tail codes
+                    # must not leak into the fused q8 clip norms.  Honest
+                    # tails are already exact zero codes — this multiply is
+                    # the identity for them.
+                    if P < src.shape[-1]:
+                        src = src * (jnp.arange(src.shape[-1])
+                                     < P).astype(src.dtype)
+                else:
+                    src = adversary_mod.corrupt_dense_batched(
+                        ac, src, cmask, rr, arrays["areq_ids"],
+                        arrays["acand_ids"])
+            if decay_on:
+                # staleness-decayed weights (gamma**lag): the stride lag of
+                # each resident image under cadence, +1 for a fault-stale
+                # delivery — closed form, no new carried state; masks are
+                # exact 0/1 factors so applying decay after them is bitwise
+                # identical to the loop engine's decay-then-mask order
+                lag = (cadence_mod.image_lag(cc, rr, arrays["cad_cand_ids"])
+                       if cadence_on else jnp.zeros((R, N), jnp.int32))
+                if faults_on:
+                    lag = lag + (delivered & stale).astype(jnp.int32)
+                round_w = protocol.decayed_round_weights(round_w, lag, gamma)
+            if robust_on:
+                # Phase.AGGREGATE hardened: the robust statistic runs on the
+                # SAME masked lane buffer the fedavg kernel would see —
+                # both engines call the one repro.kernels.robust entry, so
+                # the clipped masks are bitwise identical by construction
+                if compress_on:
+                    glob, clipped = robust_aggregate_q8(
+                        src, src_s, round_w, method=robust,
+                        use_pallas=use_pallas, interpret=interpret)
+                    glob = glob[:, :P]
+                else:
+                    glob, clipped = robust_aggregate(
+                        src, round_w, method=robust,
+                        use_pallas=use_pallas, interpret=interpret)
+            elif compress_on:
+                glob = fedavg_flat_batched_q8(
+                    src, src_s, round_w,
+                    use_pallas=use_pallas, interpret=interpret)[:, :P]
             else:
-                src = adversary_mod.corrupt_dense_batched(
-                    ac, src, cmask, rr, arrays["areq_ids"],
-                    arrays["acand_ids"])
-        if decay_on:
-            # staleness-decayed weights (gamma**lag): the stride lag of
-            # each resident image under cadence, +1 for a fault-stale
-            # delivery — closed form, no new carried state; masks are
-            # exact 0/1 factors so applying decay after them is bitwise
-            # identical to the loop engine's decay-then-mask order
-            lag = (cadence_mod.image_lag(cc, rr, arrays["cad_cand_ids"])
-                   if cadence_on else jnp.zeros((R, N), jnp.int32))
-            if faults_on:
-                lag = lag + (delivered & stale).astype(jnp.int32)
-            round_w = protocol.decayed_round_weights(round_w, lag, gamma)
-        if robust_on:
-            # Phase.AGGREGATE hardened: the robust statistic runs on the
-            # SAME masked lane buffer the fedavg kernel would see —
-            # both engines call the one repro.kernels.robust entry, so
-            # the clipped masks are bitwise identical by construction
-            if compress_on:
-                glob, clipped = robust_aggregate_q8(
-                    src, src_s, round_w, method=robust,
-                    use_pallas=use_pallas, interpret=interpret)
-                glob = glob[:, :P]
-            else:
-                glob, clipped = robust_aggregate(
-                    src, round_w, method=robust,
-                    use_pallas=use_pallas, interpret=interpret)
-        elif compress_on:
-            glob = fedavg_flat_batched_q8(
-                src, src_s, round_w,
-                use_pallas=use_pallas, interpret=interpret)[:, :P]
-        else:
-            glob = fedavg_flat_batched(src, round_w,
-                                       use_pallas=use_pallas,
-                                       interpret=interpret)
-        if adversary_on:
-            # the delivered-and-corrupted trace row: a corruption draw
-            # only counts when that link actually fed eq. (14)'s buffer
-            agg_mask = (delivered if faults_on
-                        else (member if mobility_on else arrays["asigned"]))
-            corrupted_r = cmask & agg_mask
-        if mobility_on or faults_on:
-            # nothing fed eq. (14) this round: fall back to own params,
-            # exactly like the loop engine's empty-neighborhood case
-            fed_count = dcount if faults_on else count
-            glob = jnp.where((fed_count > 0)[:, None], glob, last)
+                glob = fedavg_flat_batched(src, round_w,
+                                           use_pallas=use_pallas,
+                                           interpret=interpret)
+            if adversary_on:
+                # the delivered-and-corrupted trace row: a corruption draw
+                # only counts when that link actually fed eq. (14)'s buffer
+                agg_mask = (delivered if faults_on
+                            else (member if mobility_on
+                                  else arrays["asigned"]))
+                corrupted_r = cmask & agg_mask
+            if mobility_on or faults_on:
+                # nothing fed eq. (14) this round: fall back to own params,
+                # exactly like the loop engine's empty-neighborhood case
+                fed_count = dcount if faults_on else count
+                glob = jnp.where((fed_count > 0)[:, None], glob, last)
 
         # Phase.FIT (requesters personalize) + Phase.SCORE.  The round's
         # minibatch indices are derived here, on device, from the traced
@@ -601,89 +615,92 @@ def _make_round_fn(task, use_pallas, interpret, do_refresh, max_rounds,
         # cadence the fit seed is the LANE'S OWN round clock, not the
         # global step, so a straggler lane draws the same minibatches
         # the loop oracle draws for its r-th round.
-        if cadence_on:
-            lane_scores = jax.vmap(
-                lambda c: schedule.epoch_scores(arrays["seed0"] + c, epochs,
-                                                n_pad))(clock)
-            idx, w = jax.vmap(
-                lambda sc, n: schedule.plan_from_scores(sc, n, batch,
+        with _phase_scope(protocol.Phase.FIT):
+            if cadence_on:
+                lane_scores = jax.vmap(
+                    lambda c: schedule.epoch_scores(arrays["seed0"] + c,
+                                                    epochs, n_pad))(clock)
+                idx, w = jax.vmap(
+                    lambda sc, n: schedule.plan_from_scores(sc, n, batch,
+                                                            steps_max))(
+                    lane_scores, arrays["n_own"])
+            else:
+                scores = schedule.epoch_scores(arrays["seed0"] + rr, epochs,
+                                               n_pad)
+                idx, w = jax.vmap(
+                    lambda n: schedule.plan_from_scores(scores, n, batch,
                                                         steps_max))(
-                lane_scores, arrays["n_own"])
-        else:
-            scores = schedule.epoch_scores(arrays["seed0"] + rr, epochs,
-                                           n_pad)
-            idx, w = jax.vmap(
-                lambda n: schedule.plan_from_scores(scores, n, batch,
-                                                    steps_max))(
-                arrays["n_own"])
-        new_flat, last_loss = jax.vmap(fit_one)(
-            glob, arrays["own_x"], arrays["own_y"], idx, w)
-        acc = jax.vmap(eval_one)(new_flat, arrays["test_x"], arrays["test_y"],
-                                 arrays["test_mask"])
+                    arrays["n_own"])
+            new_flat, last_loss = jax.vmap(fit_one)(
+                glob, arrays["own_x"], arrays["own_y"], idx, w)
+        with _phase_scope(protocol.Phase.SCORE):
+            acc = jax.vmap(eval_one)(new_flat, arrays["test_x"],
+                                     arrays["test_y"], arrays["test_mask"])
 
         # Phase.ACCOUNT: traced battery discharge for executed rounds;
         # under mobility (or faults) the round energy depends on how many
         # updates actually fed eq. (14) — a host-precomputed per-count
         # table, gathered with the traced count — and every fault-world
         # drop or retry burns one MORE receive window (``e_retry``).
-        if mobility_on or faults_on:
-            e_round = jnp.take_along_axis(
-                arrays["e_tab"],
-                (dcount if faults_on else count)[:, None], axis=1)[:, 0]
-        else:
-            e_round = arrays["e_round"]
-        if faults_on:
-            e_round = e_round + (drops_r + retries_r) * arrays["e_retry"]
-        level_new = discharge_level(level, e_round,
-                                    arrays["capacity"], arrays["eff"])
-        reached = acc >= arrays["desired_accuracy"]
-        low = level_new < arrays["battery_threshold"]
-        if cadence_on:
-            # only executing lanes pay the round, advance their clocks,
-            # or may stop; ``cont`` (survives the round) still gates the
-            # final-round refresh even when the clock hits the budget —
-            # matching the loop oracle, whose last executed round still
-            # refreshes before the budget break
-            stop_code = jnp.where(exec_mask & reached,
-                                  protocol.STOP_ACCURACY,
-                                  jnp.where(exec_mask & ~reached & low,
-                                            protocol.STOP_BATTERY,
-                                            stop_code))
-            level = jnp.where(exec_mask, level_new, level)
-            rounds_done = rounds_done + exec_mask.astype(jnp.int32)
-            last = jnp.where(exec_mask[:, None], new_flat, last)
-            cont = active & ~(exec_mask & (reached | low))
-            clock_new = clock + exec_mask.astype(jnp.int32)
-            next_active = cont & (clock_new < max_rounds)
-        else:
-            stop_code = jnp.where(active & reached, protocol.STOP_ACCURACY,
-                                  jnp.where(active & ~reached & low,
-                                            protocol.STOP_BATTERY,
-                                            stop_code))
-            level = jnp.where(active, level_new, level)
-            rounds_done = rounds_done + active.astype(jnp.int32)
-            last = jnp.where(active[:, None], new_flat, last)
-            cont = next_active = active & ~reached & ~low
-            clock_new = clock
+        with _phase_scope(protocol.Phase.ACCOUNT):
+            if mobility_on or faults_on:
+                e_round = jnp.take_along_axis(
+                    arrays["e_tab"],
+                    (dcount if faults_on else count)[:, None], axis=1)[:, 0]
+            else:
+                e_round = arrays["e_round"]
+            if faults_on:
+                e_round = e_round + (drops_r + retries_r) * arrays["e_retry"]
+            level_new = discharge_level(level, e_round,
+                                        arrays["capacity"], arrays["eff"])
+            reached = acc >= arrays["desired_accuracy"]
+            low = level_new < arrays["battery_threshold"]
+            if cadence_on:
+                # only executing lanes pay the round, advance their clocks,
+                # or may stop; ``cont`` (survives the round) still gates the
+                # final-round refresh even when the clock hits the budget —
+                # matching the loop oracle, whose last executed round still
+                # refreshes before the budget break
+                stop_code = jnp.where(exec_mask & reached,
+                                      protocol.STOP_ACCURACY,
+                                      jnp.where(exec_mask & ~reached & low,
+                                                protocol.STOP_BATTERY,
+                                                stop_code))
+                level = jnp.where(exec_mask, level_new, level)
+                rounds_done = rounds_done + exec_mask.astype(jnp.int32)
+                last = jnp.where(exec_mask[:, None], new_flat, last)
+                cont = active & ~(exec_mask & (reached | low))
+                clock_new = clock + exec_mask.astype(jnp.int32)
+                next_active = cont & (clock_new < max_rounds)
+            else:
+                stop_code = jnp.where(active & reached, protocol.STOP_ACCURACY,
+                                      jnp.where(active & ~reached & low,
+                                                protocol.STOP_BATTERY,
+                                                stop_code))
+                level = jnp.where(active, level_new, level)
+                rounds_done = rounds_done + active.astype(jnp.int32)
+                last = jnp.where(active[:, None], new_flat, last)
+                cont = next_active = active & ~reached & ~low
+                clock_new = clock
 
-        # Contributor-side discharge (mobility): members paid the
-        # transmission term this round — once per ATTEMPT under faults,
-        # the sender's radio burns the same energy whether or not the
-        # update lands; the refresh term only while their requester's
-        # session survives.  Releases at the battery floor feed back
-        # into the NEXT round's membership_step.
-        if mobility_on:
-            e_tx_round = (arrays["e_tx"] * attempts.astype(jnp.float32)
-                          if faults_on else arrays["e_tx"])
-            # under cadence only members of EXECUTING lanes paid a
-            # transmission this step, and the refresh term additionally
-            # requires the contributor's own tick
-            refresh_on = (cont[:, None] & exec_mask[:, None] & ctick
-                          if cadence_on else next_active[:, None])
-            clevel = mobility_mod.contributor_discharge(
-                clevel, member & exec_mask[:, None], e_tx_round,
-                arrays["e_ref"], refresh_on,
-                mob.contributor_capacity_j)
+            # Contributor-side discharge (mobility): members paid the
+            # transmission term this round — once per ATTEMPT under faults,
+            # the sender's radio burns the same energy whether or not the
+            # update lands; the refresh term only while their requester's
+            # session survives.  Releases at the battery floor feed back
+            # into the NEXT round's membership_step.
+            if mobility_on:
+                e_tx_round = (arrays["e_tx"] * attempts.astype(jnp.float32)
+                              if faults_on else arrays["e_tx"])
+                # under cadence only members of EXECUTING lanes paid a
+                # transmission this step, and the refresh term additionally
+                # requires the contributor's own tick
+                refresh_on = (cont[:, None] & exec_mask[:, None] & ctick
+                              if cadence_on else next_active[:, None])
+                clevel = mobility_mod.contributor_discharge(
+                    clevel, member & exec_mask[:, None], e_tx_round,
+                    arrays["e_ref"], refresh_on,
+                    mob.contributor_capacity_j)
 
         # the round-(r-1) image next round's stale links will deliver:
         # snapshot the PRE-refresh round state (what this round
@@ -706,56 +723,58 @@ def _make_round_fn(task, use_pallas, interpret, do_refresh, max_rounds,
         # wire payload is dequantized into its fp32 training view and
         # the result requantized back — the round state never persists
         # at full precision.
-        if do_refresh:
-            if cadence_on:
-                # a contributor refreshes when its requester's lane
-                # executed AND survives AND the contributor itself
-                # ticked this step; signed-lane validity replaces the
-                # dedup path's lane_valid in static worlds
-                rmask = cont[:, None] & exec_mask[:, None] & ctick
-                rmask = rmask & (member if mobility_on
-                                 else arrays["cad_signed"])
-            else:
-                rmask = (next_active[:, None] & member) if mobility_on \
-                    else next_active[:, None]
-
-            def refresh(args):
-                lv, lvs, c, sc = args
-                # the training source: the live unique rows (dedup) or
-                # every lane (mobility); compressed state is dequantized
-                # into its fp32 training view here and requantized below
-                if refresh_dedup:
-                    src = (dequantize_flat_batched(lv, lvs)[:, :P]
-                           if compress_on else lv)
+        with _phase_scope(protocol.Phase.REFRESH):
+            if do_refresh:
+                if cadence_on:
+                    # a contributor refreshes when its requester's lane
+                    # executed AND survives AND the contributor itself
+                    # ticked this step; signed-lane validity replaces the
+                    # dedup path's lane_valid in static worlds
+                    rmask = cont[:, None] & exec_mask[:, None] & ctick
+                    rmask = rmask & (member if mobility_on
+                                     else arrays["cad_signed"])
                 else:
-                    src = (dequantize_flat_batched(
-                        c.reshape(R * N, -1), sc.reshape(R * N, -1))[:, :P]
-                        if compress_on else c.reshape(R * N, P))
-                refreshed, _ = jax.vmap(fit_refresh)(
-                    src, ref_rows, ref_idx, ref_w)
-                take = jnp.broadcast_to(rmask, (R, N)).reshape(R * N, 1)
-                if refresh_dedup:
-                    take = take & lane_valid
-                if compress_on:
-                    lp = c.shape[-1]
-                    q2, s2 = quantize_flat_batched(
-                        jnp.pad(refreshed, ((0, 0), (0, lp - P))),
-                        use_pallas=use_pallas, interpret=interpret)
-                    q_lane = q2[uidx_flat] if refresh_dedup else q2
-                    s_lane = s2[uidx_flat] if refresh_dedup else s2
-                    return ((q2, s2) if refresh_dedup else (lv, lvs)) + (
-                        jnp.where(take, q_lane, c.reshape(R * N, lp))
-                        .reshape(c.shape),
-                        jnp.where(take, s_lane, sc.reshape(R * N, -1))
-                        .reshape(sc.shape))
-                p_lane = refreshed[uidx_flat] if refresh_dedup else refreshed
-                return ((refreshed if refresh_dedup else lv), lvs,
-                        jnp.where(take[..., None].reshape(R, N, 1),
-                                  p_lane.reshape(R, N, P), c), sc)
+                    rmask = (next_active[:, None] & member) if mobility_on \
+                        else next_active[:, None]
 
-            live, live_s, contrib, cscale = jax.lax.cond(
-                jnp.any(rmask) if cadence_on else jnp.any(next_active),
-                refresh, lambda a: a, (live, live_s, contrib, cscale))
+                def refresh(args):
+                    lv, lvs, c, sc = args
+                    # the training source: the live unique rows (dedup) or
+                    # every lane (mobility); compressed state is dequantized
+                    # into its fp32 training view here and requantized below
+                    if refresh_dedup:
+                        src = (dequantize_flat_batched(lv, lvs)[:, :P]
+                               if compress_on else lv)
+                    else:
+                        src = (dequantize_flat_batched(
+                            c.reshape(R * N, -1), sc.reshape(R * N, -1))[:, :P]
+                            if compress_on else c.reshape(R * N, P))
+                    refreshed, _ = jax.vmap(fit_refresh)(
+                        src, ref_rows, ref_idx, ref_w)
+                    take = jnp.broadcast_to(rmask, (R, N)).reshape(R * N, 1)
+                    if refresh_dedup:
+                        take = take & lane_valid
+                    if compress_on:
+                        lp = c.shape[-1]
+                        q2, s2 = quantize_flat_batched(
+                            jnp.pad(refreshed, ((0, 0), (0, lp - P))),
+                            use_pallas=use_pallas, interpret=interpret)
+                        q_lane = q2[uidx_flat] if refresh_dedup else q2
+                        s_lane = s2[uidx_flat] if refresh_dedup else s2
+                        return ((q2, s2) if refresh_dedup else (lv, lvs)) + (
+                            jnp.where(take, q_lane, c.reshape(R * N, lp))
+                            .reshape(c.shape),
+                            jnp.where(take, s_lane, sc.reshape(R * N, -1))
+                            .reshape(sc.shape))
+                    p_lane = (refreshed[uidx_flat] if refresh_dedup
+                              else refreshed)
+                    return ((refreshed if refresh_dedup else lv), lvs,
+                            jnp.where(take[..., None].reshape(R, N, 1),
+                                      p_lane.reshape(R, N, P), c), sc)
+
+                live, live_s, contrib, cscale = jax.lax.cond(
+                    jnp.any(rmask) if cadence_on else jnp.any(next_active),
+                    refresh, lambda a: a, (live, live_s, contrib, cscale))
 
         def put(buf, row):
             return jax.lax.dynamic_update_slice_in_dim(buf, row[None], rr, 0)
@@ -862,56 +881,63 @@ def _make_round_fn(task, use_pallas, interpret, do_refresh, max_rounds,
             # prefix-stable derived schedule reproduces
             # SupervisedTask.fit's minibatches bit for bit, with padded
             # lanes (n=0) collapsing to zero-weight no-op steps.
-            scores = jax.vmap(
-                lambda j: schedule.epoch_scores(
-                    arrays["seed0"] + seed_stride * rr + j, epochs, nc_pad))(
-                jnp.arange(N, dtype=jnp.int32))
-            idx, w = jax.vmap(
-                lambda j, n: schedule.plan_from_scores(
-                    scores[j], n, batch, steps_max))(lane_j, cli_n_flat)
-            if method == "cfl":
-                # every client trains FROM THE SHARED GLOBAL (in `last`)
-                src = jnp.broadcast_to(last[:, None], (R, N, P)).reshape(R * N, P)
-            else:
-                # dfl: every node trains from its own params
-                src = contrib.reshape(R * N, P)
-            fitted, fit_loss = jax.vmap(fit_client)(src, cidx_flat, idx, w)
-            fitted = fitted.reshape(R, N, P)
+            with _phase_scope(protocol.Phase.FIT):
+                scores = jax.vmap(
+                    lambda j: schedule.epoch_scores(
+                        arrays["seed0"] + seed_stride * rr + j, epochs,
+                        nc_pad))(
+                    jnp.arange(N, dtype=jnp.int32))
+                idx, w = jax.vmap(
+                    lambda j, n: schedule.plan_from_scores(
+                        scores[j], n, batch, steps_max))(lane_j, cli_n_flat)
+                if method == "cfl":
+                    # every client trains FROM THE SHARED GLOBAL (in `last`)
+                    src = jnp.broadcast_to(last[:, None],
+                                           (R, N, P)).reshape(R * N, P)
+                else:
+                    # dfl: every node trains from its own params
+                    src = contrib.reshape(R * N, P)
+                fitted, fit_loss = jax.vmap(fit_client)(src, cidx_flat, idx, w)
+                fitted = fitted.reshape(R, N, P)
 
             # Phase.COLLECT + Phase.AGGREGATE on the flat round state:
             # cfl is one server-side data-size-weighted kernel launch;
             # dfl applies the row-stochastic mixing matrix as one launch
             # per output row (rows sum to 1, so the kernel's normalized
             # weighted mean IS the gossip mix of apply_mixing).
-            if method == "cfl":
-                glob = fedavg_flat_batched(fitted, arrays["cli_w"],
-                                           use_pallas=use_pallas,
-                                           interpret=interpret)
-                new_contrib, new_last = fitted, glob
-            else:
-                mixed = jnp.stack(
-                    [fedavg_flat_batched(fitted, arrays["mix_w"][:, k, :],
-                                         use_pallas=use_pallas,
-                                         interpret=interpret)
-                     for k in range(N)], axis=1)
-                new_contrib, new_last = mixed, mixed[:, 0]
+            with _phase_scope(protocol.Phase.AGGREGATE):
+                if method == "cfl":
+                    glob = fedavg_flat_batched(fitted, arrays["cli_w"],
+                                               use_pallas=use_pallas,
+                                               interpret=interpret)
+                    new_contrib, new_last = fitted, glob
+                else:
+                    mixed = jnp.stack(
+                        [fedavg_flat_batched(fitted, arrays["mix_w"][:, k, :],
+                                             use_pallas=use_pallas,
+                                             interpret=interpret)
+                         for k in range(N)], axis=1)
+                    new_contrib, new_last = mixed, mixed[:, 0]
 
             # Phase.SCORE: the loop oracles evaluate the aggregated
             # global (cfl) / node 0 after mixing (dfl) on requester_test
-            acc = jax.vmap(eval_one)(new_last, arrays["test_x"],
-                                     arrays["test_y"], arrays["test_mask"])
+            with _phase_scope(protocol.Phase.SCORE):
+                acc = jax.vmap(eval_one)(new_last, arrays["test_x"],
+                                         arrays["test_y"], arrays["test_mask"])
 
             # Phase.ACCOUNT without the battery term: the baselines
             # carry no battery (energy is priced host-side per session
             # via cfl_session/dfl_session), so stopping is accuracy or
             # the round budget only.
-            reached = acc >= arrays["desired_accuracy"]
-            stop_code = jnp.where(active & reached, protocol.STOP_ACCURACY,
-                                  stop_code)
-            rounds_done = rounds_done + active.astype(jnp.int32)
-            last = jnp.where(active[:, None], new_last, last)
-            contrib = jnp.where(active[:, None, None], new_contrib, contrib)
-            next_active = active & ~reached
+            with _phase_scope(protocol.Phase.ACCOUNT):
+                reached = acc >= arrays["desired_accuracy"]
+                stop_code = jnp.where(active & reached, protocol.STOP_ACCURACY,
+                                      stop_code)
+                rounds_done = rounds_done + active.astype(jnp.int32)
+                last = jnp.where(active[:, None], new_last, last)
+                contrib = jnp.where(active[:, None, None], new_contrib,
+                                    contrib)
+                next_active = active & ~reached
 
             def put(buf, row):
                 return jax.lax.dynamic_update_slice_in_dim(buf, row[None], rr, 0)
@@ -1287,6 +1313,7 @@ def run_fleet(task, requesters: Sequence[RequesterSpec],
     _sp_stage = tl.begin("stage")
 
     # ---- Phase.HANDSHAKE (host-side, static) ------------------------------
+    _sp = tl.begin("handshake")
     # Static world: sign utility-ranked contracts once.  Mobility: fix the
     # candidate POOL (agreeing devices, stable device order — the lane
     # order of both engines); membership is re-negotiated per round on
@@ -1331,8 +1358,10 @@ def run_fleet(task, requesters: Sequence[RequesterSpec],
                 np.array([d.model_staleness for d in cs], np.float32),
                 np.array([d.data_size for d in cs], np.float32),
                 np.float32(max_data)), np.float32)
+    tl.finish(_sp)
 
     # ---- contributor state / data stacks ----------------------------------
+    _sp = tl.begin("shards")
     # Shared shards are deduplicated: each unique (device, shard) pair is
     # staged once into a table, lanes carry gather indices.  At R=512
     # with one shared contributor population this removes the dominant
@@ -1377,6 +1406,8 @@ def run_fleet(task, requesters: Sequence[RequesterSpec],
     for u, (x, y) in enumerate(zip(shard_x, shard_y)):
         cx_tab[u, :len(x)] = x
         cy_tab[u, :len(y)] = y
+    tl.finish(_sp, lanes=int(sum(len(cs) for cs in lane_devs)), shards=U)
+    _sp = tl.begin("stack")
     padded_rows = [row + [None] * (N - len(row)) for row in contrib_params]
     contrib_stack = _stack_trees(
         [_stack_trees(row, template) for row in padded_rows])
@@ -1397,6 +1428,7 @@ def run_fleet(task, requesters: Sequence[RequesterSpec],
                   if (cfg.contributor_refresh_epochs > 0 and mob is None
                       and cc is None)
                   else None)
+    tl.finish(_sp)
     c_scales = None
     if wire_compress == "int8":
         lp = padded_len(P)
@@ -1414,6 +1446,7 @@ def run_fleet(task, requesters: Sequence[RequesterSpec],
     device_round_state_bytes = staged_param_bytes
 
     # ---- requester data + derived-schedule metadata -----------------------
+    _sp_arrays = tl.begin("arrays")
     own_x, _ = _pad_stack([np.asarray(s.own_train[0], np.float32) for s in requesters],
                           max(len(s.own_train[0]) for s in requesters))
     own_y, _ = _pad_stack([np.asarray(s.own_train[1], np.int32) for s in requesters],
@@ -1564,16 +1597,19 @@ def run_fleet(task, requesters: Sequence[RequesterSpec],
         arrays.update(areq_ids=jnp.asarray(areq_ids),
                       acand_ids=jnp.asarray(acand_ids),
                       asigned=jnp.asarray(asigned))
+    if ref_epochs > 0:
+        arrays.update(cx_tab=jnp.asarray(cx_tab), cy_tab=jnp.asarray(cy_tab))
+    tl.finish(_sp_arrays)
     shard_bytes = shard_bytes_dense = 0
     gather_bytes = gather_bytes_dense = 0
     index_bytes = int(n_own.nbytes + 4)
     if ref_epochs > 0:
-        arrays.update(cx_tab=jnp.asarray(cx_tab), cy_tab=jnp.asarray(cy_tab))
         if mob is None and cc is None:
             # refresh-COMPUTE dedup: lanes subscribed to the same
             # (device, shard content, staged params) follow identical
             # trajectories in a static world, so one live row per unique
             # subscription is trained and scattered to its lanes
+            _sp = tl.begin("refresh_dedup")
             ref_map: dict = {}
             ref_uidx = np.zeros((R, N), np.int32)
             lane_valid = np.zeros((R, N), bool)
@@ -1611,6 +1647,7 @@ def run_fleet(task, requesters: Sequence[RequesterSpec],
                 arrays.update(live_q0=lq, live_s0=ls)
             else:
                 arrays.update(live0=live0)
+            tl.finish(_sp, live_rows=V)
             ref_lanes = V
             idx_meta = int(ref_uidx.nbytes + 4 * 3 * V)
         else:
@@ -1633,6 +1670,7 @@ def run_fleet(task, requesters: Sequence[RequesterSpec],
         gather_bytes_dense = shard_bytes_dense
     staged = [contrib_flat] + [v for v in arrays.values() if hasattr(v, "nbytes")]
     staged_bytes = int(sum(int(v.nbytes) for v in staged))
+    tl.spans[_sp_arrays].attrs["bytes"] = staged_bytes
 
     robust = getattr(cfg, "robust", "none")
     gamma = float(getattr(cfg, "staleness_gamma", 1.0))
@@ -1641,9 +1679,10 @@ def run_fleet(task, requesters: Sequence[RequesterSpec],
                cfg.batch_size, steps_max, ref_epochs, ref_steps, ravel_spec,
                mob, cfg.n_max, cfg.strategy if mob is not None else None,
                wire_compress, P, "enfed", fc, cc, ac, robust, gamma, R, N)
-    state = _init_state("enfed", mob, ref_epochs > 0, wire_compress,
-                        cfg.max_rounds, max_events, P, fc, cc, ac, robust,
-                        contrib_flat, arrays)
+    with tl.span("init_state"):
+        state = _init_state("enfed", mob, ref_epochs > 0, wire_compress,
+                            cfg.max_rounds, max_events, P, fc, cc, ac, robust,
+                            contrib_flat, arrays)
     tl.finish(_sp_stage)
     hlo = None
     if trace is not None and getattr(trace, "hlo_stats", False):
@@ -1651,7 +1690,6 @@ def run_fleet(task, requesters: Sequence[RequesterSpec],
         # abstract shapes, so the donated carry buffers stay intact
         with tl.span("hlo_stats"):
             hlo = jit_hlo_stats(_fleet_program, *statics, state, arrays) or None
-    profiler_dir = getattr(trace, "jax_profiler_dir", None) if trace else None
     if checkpoint_dir or resume_from:
         # host-driven chunk loop: same traced round bodies, the outer
         # while moves to the host so the carry can be serialized (and a
@@ -1668,32 +1706,31 @@ def run_fleet(task, requesters: Sequence[RequesterSpec],
                 pay, _step = ckpt_mod.restore_checkpoint(resume_from, template)
             r0 = int(pay["r0"])
             state = jax.tree_util.tree_map(jnp.asarray, pay["state"])
-        with maybe_jax_profiler(profiler_dir):
-            while r0 < max_events and bool(np.any(np.asarray(state.active))):
-                before = _jit_cache_size(_fleet_chunk_program)
-                _sp = tl.begin("chunk", r0=r0)
-                state = _fleet_chunk_program(*statics, jnp.int32(r0), state,
-                                             arrays)
-                jax.block_until_ready(state)
-                _note_cache_miss(tl.spans[_sp], _fleet_chunk_program, before)
-                tl.finish(_sp)
-                r0 += chunk
-                if checkpoint_dir and r0 % every == 0:
-                    with tl.span("checkpoint_save", r0=r0):
-                        ckpt_mod.save_checkpoint(
-                            checkpoint_dir, r0,
-                            {"r0": np.int64(r0),
-                             "state": jax.tree_util.tree_map(np.asarray,
-                                                             state)})
+        while r0 < max_events and bool(np.any(np.asarray(state.active))):
+            before = _jit_cache_size(_fleet_chunk_program)
+            _sp = tl.begin("chunk", r0=r0)
+            state = _fleet_chunk_program(*statics, jnp.int32(r0), state,
+                                         arrays)
+            jax.block_until_ready(state)
+            _note_cache_miss(tl.spans[_sp], _fleet_chunk_program, before)
+            tl.finish(_sp)
+            r0 += chunk
+            if checkpoint_dir and r0 % every == 0:
+                with tl.span("checkpoint_save", r0=r0):
+                    ckpt_mod.save_checkpoint(
+                        checkpoint_dir, r0,
+                        {"r0": np.int64(r0),
+                         "state": jax.tree_util.tree_map(np.asarray,
+                                                         state)})
     else:
         before = _jit_cache_size(_fleet_program)
         _sp = tl.begin("program")
-        with maybe_jax_profiler(profiler_dir):
-            state = _fleet_program(*statics, state, arrays)
-            jax.block_until_ready(state)
+        state = _fleet_program(*statics, state, arrays)
+        jax.block_until_ready(state)
         _note_cache_miss(tl.spans[_sp], _fleet_program, before)
         tl.finish(_sp)
     _sp_unpack = tl.begin("unpack")
+    _sp = tl.begin("fetch")
     contrib_final, cscale_final = state.contrib, state.cscale
     last_flat = state.last
     acc_h, loss_h, bat_h, exec_h, body_h, member_h = (
@@ -1714,6 +1751,7 @@ def run_fleet(task, requesters: Sequence[RequesterSpec],
     rounds_np = np.asarray(state.rounds_done)
     codes_np = np.asarray(state.stop_code)
     level_np = np.asarray(state.level)
+    tl.finish(_sp)
 
     # contributor write-back: like the loop engine's in-place refresh,
     # each requester's contributor_states end up holding that session's
@@ -1722,6 +1760,7 @@ def run_fleet(task, requesters: Sequence[RequesterSpec],
     # Under compress the final state is wire format — the write-back is
     # its dequantized image, exactly what the loop engine leaves behind.
     if ref_epochs > 0:
+        _sp = tl.begin("writeback")
         if wire_compress == "int8":
             with tl.span("dequant_unpack"):
                 contrib_final = dequantize_flat_batched(
@@ -1732,10 +1771,14 @@ def run_fleet(task, requesters: Sequence[RequesterSpec],
             for j, c in enumerate(cs):
                 spec.contributor_states[c.device_id]["params"] = (
                     jax.tree_util.tree_map(lambda l: l[i, j], contrib_tree))
+        tl.finish(_sp, views=int(sum(len(cs) for cs in lane_devs)))
+
+    with tl.span("unravel"):
+        last_p = tree_unravel(ravel_spec, last_flat)
+    tl.finish(_sp_unpack)
 
     # ---- per-session views (loop-engine-compatible SessionResults) --------
-    last_p = tree_unravel(ravel_spec, last_flat)
-    tl.finish(_sp_unpack)
+    _sp = tl.begin("views", sessions=R)
     sessions = []
     total_e = 0.0
     for i, (spec, cs, b0) in enumerate(zip(requesters, lane_devs, batteries)):
@@ -1824,6 +1867,7 @@ def run_fleet(task, requesters: Sequence[RequesterSpec],
         fleet_hist.update(corrupted=corrupt_h)
     if robust != "none":
         fleet_hist.update(clipped=clip_h)
+    tl.finish(_sp)
     return FleetResult(
         sessions=sessions, rounds=rounds_np, stop_codes=codes_np,
         accuracy=np.array([s.accuracy for s in sessions], np.float32),
@@ -1976,10 +2020,8 @@ def _run_fleet_baseline(task, requesters: Sequence[RequesterSpec], cfg, cost,
             hlo = jit_hlo_stats(_fleet_program, *statics, state0, arrays) or None
     before = _jit_cache_size(_fleet_program)
     _sp = tl.begin("program")
-    with maybe_jax_profiler(getattr(trace, "jax_profiler_dir", None)
-                            if trace else None):
-        state = _fleet_program(*statics, state0, arrays)
-        jax.block_until_ready(state)
+    state = _fleet_program(*statics, state0, arrays)
+    jax.block_until_ready(state)
     _note_cache_miss(tl.spans[_sp], _fleet_program, before)
     tl.finish(_sp)
     _sp_unpack = tl.begin("unpack")

@@ -17,11 +17,15 @@ differently per engine.  This package is the one observability surface:
 
 * **Timing spans** (:mod:`repro.telemetry.spans`) — a host-side
   :class:`Timeline` of nested :class:`Span` records instrumenting the
-  real cost centers: jit trace/compile + warm execution ("program" /
-  "chunk"), shard staging ("stage"), quantize/dequantize packing
-  ("quantize_pack" / "dequant_unpack"), checkpoint I/O
-  ("checkpoint_save" / "checkpoint_restore"), and the loop engine's
-  AES-CTR transport ("transport").  ``FleetResult.timeline`` /
+  real cost centers: the world copy ("copy_world"), shard staging
+  ("stage" and its children), jit trace/compile + warm execution
+  ("program" / "chunk"), unpacking ("unpack" and its children), the
+  per-session views ("views") and the result ("assemble"),
+  quantize/dequantize packing ("quantize_pack" / "dequant_unpack"),
+  checkpoint I/O ("checkpoint_save" / "checkpoint_restore"), and the
+  loop engine's AES-CTR transport ("transport").  Every span is also a
+  ``jax.profiler.TraceAnnotation``, so a profiler trace holds the spans
+  on the device's clock.  ``FleetResult.timeline`` /
   ``RunResult.timeline`` carry it; ``Timeline.totals()`` is the
   wall-clock breakdown the bench publishes.
 
@@ -30,9 +34,11 @@ differently per engine.  This package is the one observability surface:
   Timeline as a Chrome-trace/Perfetto ``trace.json``.
 
 * **Profiling hooks** (:mod:`repro.telemetry.profile`) — an opt-in
-  ``jax.profiler`` trace around the fleet program and an ``hlo_stats``
-  summary (flops / bytes-accessed / memory of the compiled program,
-  via :mod:`repro.launch.hlo_stats`).
+  ``jax.profiler`` trace around the whole ``Experiment.run`` and an
+  ``hlo_stats`` summary (flops / bytes-accessed / memory of the
+  compiled program, via :mod:`repro.launch.hlo_stats`, and each
+  instruction's protocol phase from the fleet program's
+  ``jax.named_scope`` per ``Phase``).
 
 * **The knob** (:class:`TraceConfig` on ``ExecutionSpec.trace``) —
   selects exports and profiling hooks per run.
@@ -52,7 +58,8 @@ from repro.telemetry.events import (EVENT_PHASES, ROUND_EVENT_FIELDS,
                                     session_events, validate_events)
 from repro.telemetry.export import (read_events_jsonl, timeline_chrome_trace,
                                     write_chrome_trace, write_events_jsonl)
-from repro.telemetry.profile import jit_hlo_stats, maybe_jax_profiler
+from repro.telemetry.profile import (hlo_phases, jit_hlo_stats,
+                                     maybe_jax_profiler)
 from repro.telemetry.spans import Span, Timeline
 
 __all__ = [
@@ -70,5 +77,6 @@ __all__ = [
     "timeline_chrome_trace",
     "write_chrome_trace",
     "jit_hlo_stats",
+    "hlo_phases",
     "maybe_jax_profiler",
 ]

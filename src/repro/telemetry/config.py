@@ -22,16 +22,21 @@ class TraceConfig:
 
     ``events_jsonl`` / ``chrome_trace`` are written by the
     ``Experiment.run`` facade after the run completes (host-side file
-    I/O, outcome-neutral).  ``jax_profiler_dir`` wraps the fleet
-    program's execution in ``jax.profiler.trace`` (fleet engine only —
-    the loop engine warns and ignores it).  ``hlo_stats`` lowers and
-    compiles the fleet program a second time through the AOT API to
-    report flops/bytes (:mod:`repro.launch.hlo_stats`) — nothing is
-    executed, but the extra compile makes it strictly opt-in.
+    I/O, outcome-neutral).  ``jax_profiler_dir`` wraps the whole
+    ``Experiment.run``, on either engine, in ``jax.profiler.trace``
+    with the Python tracer off: the run's ``Timeline`` spans are host
+    events of that trace, beside the device's operations, so staging,
+    the program, unpacking and the facade all show on one clock.
+    ``hlo_stats`` lowers and compiles the fleet program a second time
+    through the AOT API to report flops/bytes
+    (:mod:`repro.launch.hlo_stats`) and each instruction's protocol
+    phase (``phases``) — nothing is executed, but the extra compile
+    makes it strictly opt-in (fleet engine only — the loop engine warns
+    and ignores it).
     """
 
     events_jsonl: Optional[str] = None   # write the RoundEvent stream here
     chrome_trace: Optional[str] = None   # write the Timeline as trace.json
-    jax_profiler_dir: Optional[str] = None  # jax.profiler.trace around the
-                                            # fleet program (fleet only)
+    jax_profiler_dir: Optional[str] = None  # jax.profiler.trace around
+                                            # the whole Experiment.run
     hlo_stats: bool = False              # attach compiled-program flops/bytes

@@ -11,22 +11,25 @@ schema-valid, and the Timeline span stack must behave.
 
 import copy
 import dataclasses
+import glob
 import json
+import os
 
 import numpy as np
 import pytest
 from jax.flatten_util import ravel_pytree
 
 from repro.api import ExecutionSpec, Experiment, MethodSpec, WorldSpec
-from repro.core import (FaultConfig, MobilityConfig, SupervisedTask,
-                        make_fleet)
+from repro.core import (FaultConfig, MobilityConfig, RequesterSpec,
+                        SupervisedTask, make_fleet)
 from repro.data import (CaloriesDatasetConfig, dirichlet_partition,
                         make_calories_tabular)
 from repro.models import MLPClassifier, MLPClassifierConfig
 from repro.telemetry import (EVENT_PHASES, RoundEvent, Timeline, TraceConfig,
-                             compare_event_streams, read_events_jsonl,
-                             timeline_chrome_trace, validate_events,
-                             write_chrome_trace, write_events_jsonl)
+                             compare_event_streams, hlo_phases,
+                             read_events_jsonl, timeline_chrome_trace,
+                             validate_events, write_chrome_trace,
+                             write_events_jsonl)
 
 from test_cadence import CC_SLOW_REQ
 
@@ -118,6 +121,7 @@ def test_trace_on_is_bitwise_identical_to_trace_off(problem, engine,
     # included); the loop engine gets the exports that apply to it
     trace = TraceConfig(events_jsonl=str(tmp_path / "events.jsonl"),
                         chrome_trace=str(tmp_path / "trace.json"),
+                        jax_profiler_dir=str(tmp_path / "profile"),
                         hlo_stats=(engine == "fleet"))
     off = Experiment(_world(problem, mobility), method,
                      ExecutionSpec(engine=engine)).run()
@@ -127,6 +131,7 @@ def test_trace_on_is_bitwise_identical_to_trace_off(problem, engine,
     # and the traced run actually observed something
     assert (tmp_path / "events.jsonl").exists()
     assert (tmp_path / "trace.json").exists()
+    assert _xplane(tmp_path / "profile")
     assert on.timings
     if engine == "fleet":
         assert on.hlo_stats and "flops" in on.hlo_stats
@@ -315,6 +320,154 @@ def test_open_span_excluded_from_totals_and_trace():
     tl.begin("open")
     assert tl.totals() == {}
     assert timeline_chrome_trace(tl)["traceEvents"] == []
+
+
+# ---------------------------------------------------------------------------
+# the spans of one fleet study, on the profiler's clock
+# ---------------------------------------------------------------------------
+
+STUDY_SPANS = ["copy_world", "stage", "program", "unpack", "views", "assemble"]
+STAGE_SPANS = ["handshake", "shards", "stack", "arrays", "refresh_dedup",
+               "init_state"]
+UNPACK_SPANS = ["fetch", "writeback", "unravel"]
+
+
+def _shared_world(problem, n_req=2):
+    """``n_req`` requesters over one contributor population: every lane
+    of a device stages the same shard and the same params, so the staged
+    shards and refresh rows are the devices, not the lanes."""
+    task, own_train, own_test, fleet, states = problem
+    return WorldSpec(task=task, requesters=[
+        RequesterSpec(own_train, own_test, fleet, copy.deepcopy(states))
+        for _ in range(n_req)])
+
+
+def _children(tl, idx):
+    return [s for s in tl.spans if s.parent == idx]
+
+
+def _xplane(log_dir):
+    return glob.glob(os.path.join(log_dir, "plugins", "profile", "*",
+                                  "*.xplane.pb"))
+
+
+def test_fleet_study_span_tree(problem):
+    """One study's spans, in order, with the counts their parents' work
+    produced — equal to what the FleetResult reports."""
+    res = Experiment(_shared_world(problem), _METHOD,
+                     ExecutionSpec(engine="fleet")).run()
+    tl, fr = res.timeline, res.raw
+    top = [i for i, s in enumerate(tl.spans) if s.parent is None]
+    assert [tl.spans[i].name for i in top] == STUDY_SPANS
+    by_name = {tl.spans[i].name: i for i in top}
+    stage = _children(tl, by_name["stage"])
+    unpack = _children(tl, by_name["unpack"])
+    assert [s.name for s in stage] == STAGE_SPANS
+    assert [s.name for s in unpack] == UNPACK_SPANS
+    attrs = {s.name: s.attrs for s in tl.spans}
+    n_devices = len(problem[4])
+    lanes = sum(s.n_contributors for s in fr.sessions)
+    assert lanes == 2 * n_devices
+    assert attrs["shards"] == {"lanes": lanes, "shards": n_devices}
+    assert attrs["refresh_dedup"] == {"live_rows": n_devices}
+    assert attrs["arrays"] == {"bytes": fr.staged_host_bytes}
+    assert attrs["writeback"] == {"views": lanes}
+    assert attrs["views"] == {"sessions": len(fr.sessions)}
+    # children lie inside their parents, the study inside its wall time
+    for s in tl.spans:
+        if s.parent is not None:
+            p = tl.spans[s.parent]
+            assert p.t0 <= s.t0 and s.t0 + s.dur <= p.t0 + p.dur
+    assert sum(tl.spans[i].dur for i in top) <= res.wall_s
+
+
+def test_study_spans_are_host_events_of_a_profiler_trace(problem, tmp_path):
+    """Under a jax.profiler trace each Timeline span is a host event of
+    the same name, nested in the study's annotation and in its parent's
+    event, in the Timeline's order."""
+    import jax
+    exp = Experiment(_shared_world(problem), _METHOD,
+                     ExecutionSpec(engine="fleet"))
+    exp.run()                                      # compile outside
+    with jax.profiler.trace(str(tmp_path)):
+        with jax.profiler.TraceAnnotation("study"):
+            res = exp.run()
+    [path] = _xplane(tmp_path)
+    profile = jax.profiler.ProfileData.from_file(path)
+    names = {s.name for s in res.timeline.spans} | {"study"}
+    [events] = [ev for plane in profile.planes
+                if plane.name.startswith("/host:") for line in plane.lines
+                for ev in [[(e.name, e.start_ns, e.start_ns + e.duration_ns)
+                            for e in line.events if e.name in names]] if ev]
+    events.sort(key=lambda e: (e[1], -e[2]))
+    (study, s0, s1), spans = events[0], events[1:]
+    assert study == "study"
+    assert [e[0] for e in spans] == [s.name for s in res.timeline.spans]
+    for (name, a, b), sp in zip(spans, res.timeline.spans):
+        pa, pb = (s0, s1) if sp.parent is None else spans[sp.parent][1:]
+        assert pa <= a and b <= pb, name
+
+
+@pytest.mark.parametrize("engine", ["loop", "fleet"])
+def test_profiler_knob_wraps_the_whole_run(problem, engine, tmp_path,
+                                           recwarn):
+    """``TraceConfig.jax_profiler_dir`` profiles the whole
+    ``Experiment.run`` on either engine, world copy to result, and the
+    loop engine takes it without a warning."""
+    import jax
+    res = Experiment(_world(problem), _METHOD, ExecutionSpec(
+        engine=engine, trace=TraceConfig(jax_profiler_dir=str(tmp_path))
+    )).run()
+    assert not [w for w in recwarn if "TraceConfig" in str(w.message)]
+    [path] = _xplane(tmp_path)
+    profile = jax.profiler.ProfileData.from_file(path)
+    seen = [e.name for plane in profile.planes
+            if plane.name.startswith("/host:") for line in plane.lines
+            for e in line.events]
+    top = [s.name for s in res.timeline.spans if s.parent is None]
+    assert top[0] == "copy_world" and top[-1] == "assemble"
+    assert set(top) <= set(seen)
+
+
+def test_fleet_program_names_its_protocol_phases(problem):
+    """Every protocol phase the static enfed round runs is a
+    ``jax.named_scope``: the compiled program's instructions map to
+    them (``hlo_stats["phases"]``)."""
+    res = Experiment(_world(problem), _METHOD, ExecutionSpec(
+        engine="fleet", trace=TraceConfig(hlo_stats=True))).run()
+    phases = res.hlo_stats["phases"]
+    assert {"fit", "score", "aggregate", "refresh", "account",
+            "other"} <= set(phases.values())
+    assert not {"renegotiate", "deliver"} & set(phases.values())
+
+
+def test_hlo_phases_reads_scopes_and_fusion_roots():
+    hlo = """HloModule jit_f
+
+%fused_computation (param_0: f32[8]) -> f32[8] {
+  %param_0 = f32[8]{0} parameter(0)
+  ROOT %add.1 = f32[8]{0} add(%param_0, %param_0), metadata={op_name="jit(f)/while/body/transpose(jvp(fit))/add"}
+}
+
+%fused_computation.2 (param_0.2: f32[8]) -> (f32[8], f32[8]) {
+  %param_0.2 = f32[8]{0} parameter(0)
+  %mul.2 = f32[8]{0} multiply(%param_0.2, %param_0.2), metadata={op_name="jit(f)/refresh/vmap(fit_refresh)/mul"}
+  ROOT %tuple.2 = (f32[8]{0}, f32[8]{0}) tuple(%mul.2, %param_0.2)
+}
+
+ENTRY %main.9 (x.1: f32[8]) -> f32[8] {
+  %x.1 = f32[8]{0} parameter(0)
+  %add_fusion = f32[8]{0} fusion(%x.1), kind=kLoop, calls=%fused_computation, metadata={op_name="jit(f)/other_scope/add"}
+  %multi_fusion = (f32[8]{0}, f32[8]{0}) fusion(%add_fusion), kind=kLoop, calls=%fused_computation.2
+  %get-tuple-element.3 = f32[8]{0} get-tuple-element(%multi_fusion), index=0
+  ROOT %reduce.4 = f32[8]{0} negate(%get-tuple-element.3), metadata={op_name="jit(f)/score/epoch_scores/neg"}
+}
+"""
+    phases = hlo_phases(hlo)
+    assert phases["add_fusion"] == "fit"        # its root's scope
+    assert phases["multi_fusion"] == "refresh"  # the tuple root's operand
+    assert phases["reduce.4"] == "score"        # not "epoch_scores"
+    assert phases["x.1"] == phases["get-tuple-element.3"] == "other"
 
 
 # ---------------------------------------------------------------------------
