@@ -1218,6 +1218,13 @@ def run_fleet(task, requesters: Sequence[RequesterSpec],
     early-exit granularity: the compiled round loop re-checks "is any
     session still active?" every ``round_chunk`` rounds.
 
+    With ``cfg.contributor_refresh_epochs > 0`` each requester's
+    ``contributor_states[d]["params"]`` is rebound, as the loop engine's
+    refresh does, to that session's final contributor params: read-only
+    host (numpy) views of one device-to-host copy of the round state,
+    not device arrays.  Requesters that share one states dict see the
+    last requester's lanes.
+
     With ``cfg.mobility`` set, contributor lanes hold each requester's
     candidate pool and membership churns on device — requester lane i
     moves as device ``cfg.mobility.requester_id + i`` in the shared
@@ -1759,6 +1766,11 @@ def run_fleet(task, requesters: Sequence[RequesterSpec],
     # Requesters sharing one states dict see the last writer's lanes.
     # Under compress the final state is wire format — the write-back is
     # its dequantized image, exactly what the loop engine leaves behind.
+    # The (R, N, P) state comes to the host in ONE transfer; each lane's
+    # tree is numpy views of the unraveled leaves, with no device
+    # dispatch.  The leaves are read-only: every lane's tree is a view of
+    # them, and states dicts hold param trees as immutable values
+    # (WorldSpec.fresh_requesters shares them between runs).
     if ref_epochs > 0:
         _sp = tl.begin("writeback")
         if wire_compress == "int8":
@@ -1766,12 +1778,16 @@ def run_fleet(task, requesters: Sequence[RequesterSpec],
                 contrib_final = dequantize_flat_batched(
                     contrib_final, cscale_final)[..., :P]
                 jax.block_until_ready(contrib_final)
-        contrib_tree = tree_unravel(ravel_spec, contrib_final)
+        contrib_host = np.asarray(contrib_final)
+        contrib_tree = tree_unravel(ravel_spec, contrib_host)
+        for leaf in jax.tree_util.tree_leaves(contrib_tree):
+            leaf.setflags(write=False)
         for i, (spec, cs) in enumerate(zip(requesters, lane_devs)):
             for j, c in enumerate(cs):
                 spec.contributor_states[c.device_id]["params"] = (
                     jax.tree_util.tree_map(lambda l: l[i, j], contrib_tree))
-        tl.finish(_sp, views=int(sum(len(cs) for cs in lane_devs)))
+        tl.finish(_sp, views=int(sum(len(cs) for cs in lane_devs)),
+                  bytes=int(contrib_host.nbytes))
 
     with tl.span("unravel"):
         last_p = tree_unravel(ravel_spec, last_flat)

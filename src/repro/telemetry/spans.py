@@ -39,8 +39,9 @@ indented names nest in the one above them; attrs in brackets):
 ``checkpoint_restore``     checkpoint restore (both engines)
 ``unpack``                 fleet device->host unpacking, parent of:
   ``fetch``                histories, rounds, stop codes, levels to host
-  ``writeback``            contributor write-back [``views``: per-lane
-                           trees written]
+  ``writeback``            contributor write-back: one device->host copy,
+                           per-lane numpy views [``views``: per-lane trees
+                           written, ``bytes``: bytes fetched]
     ``dequant_unpack``     int8->fp32 write-back dequant
   ``unravel``              the final params' pytree
 ``views``                  fleet per-session ``SessionResult`` and

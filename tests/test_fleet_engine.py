@@ -12,6 +12,7 @@ three stop conditions.
 import copy
 import dataclasses
 
+import jax
 import numpy as np
 import pytest
 from jax.flatten_util import ravel_pytree
@@ -141,6 +142,56 @@ def test_fleet_writes_back_refreshed_contributors(problem):
         assert not np.allclose(np.asarray(lv), np.asarray(before)), "refresh ran"
         np.testing.assert_allclose(np.asarray(fv), np.asarray(lv),
                                    rtol=1e-4, atol=1e-5)
+
+
+@pytest.mark.parametrize("compress,tol", [
+    (None, dict(rtol=1e-4, atol=1e-5)),
+    ("int8", dict(atol=1e-2)),          # the wire image, as test_compress
+], ids=["fp32", "int8"])
+def test_fleet_multi_requester_writeback_is_read_only_host_views(
+        problem, compress, tol):
+    """R = 3 requesters, each with its own shard and its own shallow copy
+    of the states dict (as ``WorldSpec.fresh_requesters`` makes): each
+    dict ends up holding that requester's loop-engine refresh, as
+    read-only numpy leaves; one dict shared by all three holds the last
+    requester's lanes."""
+    task, own_train, own_test, fleet, states = problem
+    cfg = EnFedConfig(desired_accuracy=0.99, max_rounds=3, epochs=1,
+                      batch_size=BATCH, encrypt=False,
+                      contributor_refresh_epochs=1, compress=compress)
+    shards = [(own_train[0][i::3], own_train[1][i::3]) for i in range(3)]
+    # the last requester's battery stops it after one round, so its
+    # contributors' final params differ from the other two's
+    battery_kw = [{}, {}, dict(capacity_j=0.2, level=0.3)]
+
+    def specs(dicts):
+        return [RequesterSpec(sh, own_test, fleet, st, BatteryState(**kw))
+                for sh, st, kw in zip(shards, dicts, battery_kw)]
+
+    own = [{k: dict(v) for k, v in states.items()} for _ in shards]
+    run_fleet(task, specs(own), cfg)
+    for sh, st, kw in zip(shards, own, battery_kw):
+        loop_states = copy.deepcopy(states)
+        EnFedSession(task, sh, own_test, fleet, loop_states, cfg,
+                     battery=BatteryState(**kw)).run()
+        for dev_id in states:
+            leaves = jax.tree_util.tree_leaves(st[dev_id]["params"])
+            assert all(isinstance(l, np.ndarray) and not l.flags.writeable
+                       for l in leaves)
+            with pytest.raises(ValueError):
+                leaves[0][...] = 0.0
+            lv, _ = ravel_pytree(loop_states[dev_id]["params"])
+            fv, _ = ravel_pytree(st[dev_id]["params"])
+            np.testing.assert_allclose(np.asarray(fv), np.asarray(lv), **tol)
+    first, last = ([ravel_pytree(o[d]["params"])[0] for d in states]
+                   for o in (own[0], own[-1]))
+    assert not all(np.array_equal(a, b) for a, b in zip(first, last))
+    shared = {k: dict(v) for k, v in states.items()}
+    run_fleet(task, specs([shared] * len(shards)), cfg)
+    for dev_id in states:
+        jax.tree_util.tree_map(np.testing.assert_array_equal,
+                               shared[dev_id]["params"],
+                               own[-1][dev_id]["params"])
 
 
 def test_session_fleet_engine_flag(problem):

@@ -371,7 +371,12 @@ def test_fleet_study_span_tree(problem):
     assert attrs["shards"] == {"lanes": lanes, "shards": n_devices}
     assert attrs["refresh_dedup"] == {"live_rows": n_devices}
     assert attrs["arrays"] == {"bytes": fr.staged_host_bytes}
-    assert attrs["writeback"] == {"views": lanes}
+    # one device->host copy of the final (R, N, P) fp32 contributor
+    # state, N = the n_max contract slots, signed or not
+    n_params = ravel_pytree(next(iter(problem[4].values()))["params"])[0].size
+    assert attrs["writeback"] == {
+        "views": lanes,
+        "bytes": len(fr.sessions) * _METHOD.n_max * n_params * 4}
     assert attrs["views"] == {"sessions": len(fr.sessions)}
     # children lie inside their parents, the study inside its wall time
     for s in tl.spans:
